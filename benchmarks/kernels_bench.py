@@ -1,6 +1,6 @@
-"""Pallas kernel microbenchmarks (interpret mode).
+"""Pallas kernel microbenchmarks (interpreted on the CPU backend).
 
-CPU interpret timings are NOT TPU performance; the value of these rows is
+CPU interpreter timings are NOT TPU performance; the value of these rows is
 (a) exercising every kernel end-to-end from the benchmark harness and
 (b) reporting the kernels' modeled HBM traffic (the quantity the runahead
 design optimizes).  TPU wall-time belongs to real-hardware runs.

@@ -40,7 +40,7 @@ def main():
     print(f" cycles {base.cycles} -> {new.cycles} "
           f"({(base.cycles-new.cycles)/base.cycles:+.2%})")
 
-    print("\n== 3. TPU adaptation: runahead gather (Pallas, interpret) ==")
+    print("\n== 3. TPU adaptation: runahead gather (Pallas) ==")
     rng = np.random.default_rng(0)
     table = jnp.asarray(rng.normal(size=(1024, 128)), jnp.float32)
     idx = jnp.asarray(rng.integers(0, 1024, 64), jnp.int32)

@@ -4,15 +4,18 @@ train step, runahead data loader, async checkpointing, straggler watchdog,
 crash recovery.
 
 Usage:
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
   PYTHONPATH=src python examples/train_lm.py --steps 300 --arch qwen2-1.5b
 
-(Defaults are sized for CPU smoke: a reduced-width model, 200 steps.  On a
-real TPU slice, drop --reduced and point --mesh at the production shape.)
+  # published widths on a TPU host (4 chips: a (data 2, model 2) mesh)
+  PYTHONPATH=src python examples/train_lm.py --no-reduced --seq 1024
+
+(Defaults are sized for CPU smoke: a reduced-width model, 200 steps.  The
+params and AdamW moments are created already sharded over the mesh, so the
+full-width state never sits on one device.)
 """
 import argparse
 import dataclasses
-import pathlib
 import tempfile
 import time
 
@@ -21,11 +24,10 @@ import jax
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs import registry
 from repro.data.pipeline import RunaheadLoader, synthetic_batch
+from repro.launch import compile_cache
 from repro.launch.mesh import make_host_mesh
-from repro.launch.steps import build_train_step, make_optimizer
-from repro.models import api
+from repro.launch.steps import build_train_step, init_train_state
 from repro.models.types import ShapeConfig
-from repro.optim import adamw
 from repro.runtime.fault_tolerance import StragglerWatchdog, TrainDriver
 from repro.sharding.rules import MeshRules
 
@@ -48,10 +50,14 @@ def main():
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="~100M-param reduced width (--no-reduced: the "
+                         "published widths)")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
 
+    compile_cache.enable()
     cfg = build_100m_config(args.arch, args.reduced)
     shape = ShapeConfig("train_custom", "train", args.seq, args.batch)
     n_dev = len(jax.devices())
@@ -59,14 +65,11 @@ def main():
         if n_dev > 1 else make_host_mesh(1, 1)
     rules = MeshRules(mesh, sequence_parallel=False)
     built = build_train_step(cfg, shape, rules)
-    opt = make_optimizer(cfg)
 
-    params = api.init_params(jax.random.key(0), cfg)
-    n_params = sum(int(x.size) for x in jax.tree.leaves(params))
+    state = init_train_state(cfg, rules, jax.random.key(0))
+    n_params = sum(int(x.size) for x in jax.tree.leaves(state["params"]))
     print(f"arch={cfg.name} params={n_params/1e6:.1f}M devices={n_dev} "
           f"mesh={dict(mesh.shape)}")
-    state = adamw.init_state(params, opt)
-    state = jax.device_put(state, rules.named(rules.state_specs(state)))
 
     loader = RunaheadLoader(
         lambda step: synthetic_batch(cfg, shape, seed=0, step=step), depth=2)
@@ -78,8 +81,7 @@ def main():
     driver = TrainDriver(built.fn, loader.get, ck, checkpoint_every=50,
                          watchdog=wd)
     t0 = time.time()
-    with mesh:
-        state, hist = driver.run(state, args.steps)
+    state, hist = driver.run(state, args.steps)
     dt = time.time() - t0
     first, last = hist[0]["loss"], hist[-1]["loss"]
     print(f"steps={len(hist)} loss {first:.3f} -> {last:.3f} "

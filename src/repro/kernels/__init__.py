@@ -1,3 +1,13 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels for the memory-bound hot spots (runahead gather,
+paged / flash attention, MoE dispatch, SSD scan).
+
+Each ``ops.py`` wrapper compiles its kernel through Mosaic for the TPU and
+runs it in the Pallas interpreter only on the CPU backend (tests and CPU
+examples); :func:`interpret_mode` is the one place that decides.
+"""
+import jax
+
+
+def interpret_mode() -> bool:
+    """True when Pallas kernels must run interpreted: the CPU backend."""
+    return jax.default_backend() == "cpu"

@@ -68,7 +68,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int | None = None,
                     q_block: int = 128, kv_block: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """q,k,v: [B,H,S,D] -> [B,H,S,D]."""
     b, h, sq, d = q.shape
     sk = k.shape[2]

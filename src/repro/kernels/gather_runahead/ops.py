@@ -5,29 +5,27 @@ import functools
 
 import jax
 
+from .. import interpret_mode
 from . import gather_runahead as k
 from . import ref
 
 
-@functools.partial(jax.jit, static_argnames=("impl", "block_rows", "depth",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("impl", "block_rows", "depth"))
 def gather(table, idx, *, impl: str = "runahead", block_rows: int = 8,
-           depth: int = 2, interpret: bool = True):
+           depth: int = 2):
     """out[i] = table[idx[i]] with runahead prefetch.
 
     impl: "runahead" (explicit multi-buffered DMA; ``depth`` = in-flight
-    fetches, the MSHR analogue), "pipelined" (BlockSpec pipeline), or
-    "reference" (jnp oracle).
+    fetches, the MSHR analogue) or "reference" (jnp oracle).
     """
     if impl == "reference":
         return ref.gather_ref(table, idx)
-    if impl == "pipelined":
-        return k.pipelined_gather(table, idx, interpret=interpret)
     return k.runahead_gather(table, idx, block_rows=block_rows, depth=depth,
-                             interpret=interpret)
+                             interpret=interpret_mode())
 
 
-@functools.partial(jax.jit, static_argnames=("depth", "interpret"))
-def gather_bag(table, idx, weights, *, depth: int = 2, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("depth",))
+def gather_bag(table, idx, weights, *, depth: int = 2):
     """Listing-1 aggregation: out[s] = sum_k w[s,k] * table[idx[s,k]]."""
-    return k.gather_bag(table, idx, weights, depth=depth, interpret=interpret)
+    return k.gather_bag(table, idx, weights, depth=depth,
+                        interpret=interpret_mode())

@@ -12,6 +12,9 @@ def gather_ref(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
 def gather_bag_ref(table: jnp.ndarray, idx: jnp.ndarray,
                    weights: jnp.ndarray) -> jnp.ndarray:
     """Padded-CSR aggregation: out[s] = sum_k w[s,k] * table[idx[s,k]]
-    (GCN ``aggregate`` / embedding-bag).  idx: [S,K]; weights: [S,K]."""
+    (GCN ``aggregate`` / embedding-bag).  idx: [S,K]; weights: [S,K],
+    rounded to the table dtype; the sum is taken in float32."""
     rows = jnp.take(table, idx, axis=0)              # [S, K, D]
-    return jnp.einsum("sk,skd->sd", weights.astype(rows.dtype), rows)
+    out = jnp.einsum("sk,skd->sd", weights.astype(rows.dtype), rows,
+                     preferred_element_type=jnp.float32)
+    return out.astype(table.dtype)

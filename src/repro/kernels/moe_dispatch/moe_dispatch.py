@@ -31,7 +31,7 @@ def _dispatch_kernel(slot_ref, x_ref, o_ref, sem, *, n_tokens: int,
 
 
 def dispatch(x: jax.Array, slot: jax.Array, n_slots: int, *,
-             interpret: bool = True) -> jax.Array:
+             interpret: bool) -> jax.Array:
     """x: [T,D]; slot: [T] in [0,n_slots) or -1 -> [n_slots, D]."""
     t, d = x.shape
     kernel = functools.partial(_dispatch_kernel, n_tokens=t, n_slots=n_slots)
@@ -86,7 +86,7 @@ def _combine_kernel(slot_ref, w_ref, ye_ref, o_ref, scratch, sems, *,
 
 
 def combine(ye: jax.Array, slot: jax.Array, weights: jax.Array, *,
-            depth: int = 2, interpret: bool = True) -> jax.Array:
+            depth: int = 2, interpret: bool) -> jax.Array:
     """ye: [n_slots,D]; slot,weights: [T,K] -> [T,D]."""
     t, fanin = slot.shape
     d = ye.shape[1]

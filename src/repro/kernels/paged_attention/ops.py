@@ -5,15 +5,16 @@ import functools
 
 import jax
 
+from .. import interpret_mode
 from . import paged_attention as k
 from . import ref
 
 
-@functools.partial(jax.jit, static_argnames=("impl", "interpret"))
+@functools.partial(jax.jit, static_argnames=("impl",))
 def paged_attention(q, k_pages, v_pages, page_table, lengths, *,
-                    impl: str = "pallas", interpret: bool = True):
+                    impl: str = "pallas"):
     if impl == "reference":
         return ref.paged_attention_ref(q, k_pages, v_pages, page_table,
                                        lengths)
     return k.paged_attention(q, k_pages, v_pages, page_table, lengths,
-                             interpret=interpret)
+                             interpret=interpret_mode())

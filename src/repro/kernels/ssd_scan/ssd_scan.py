@@ -60,7 +60,7 @@ def _ssd_kernel(x_ref, dt_ref, la_ref, b_ref, c_ref, alog_ref, dskip_ref,
 
 
 def ssd_scan(xh, dt, a_log, b_mat, c_mat, d_skip, *, chunk: int = 64,
-             interpret: bool = True):
+             interpret: bool):
     """xh: [B,S,H,P]; dt: [B,S,H]; b/c: [B,S,N]; returns y [B,S,H,P]."""
     bsz, s, h, p = xh.shape
     n = b_mat.shape[-1]

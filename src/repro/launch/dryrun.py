@@ -12,7 +12,7 @@ friends below.  Run as:
 
 Artifacts (memory_analysis, cost_analysis, collective bytes, op census) are
 written to artifacts/dryrun/<arch>__<shape>__<mesh>.json; the roofline
-benchmark (benchmarks/roofline.py) and EXPERIMENTS.md §Dry-run read them.
+benchmark (benchmarks/roofline.py) reads them.
 """
 import argparse
 import dataclasses
@@ -20,8 +20,6 @@ import json
 import pathlib
 import time
 import traceback
-
-import jax
 
 from repro.configs import registry
 from repro.launch import hlo
@@ -53,15 +51,11 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     t0 = time.time()
     mesh = make_production_mesh(multi_pod=multi_pod)
     rules = MeshRules(mesh, multi_pod=multi_pod, **(rules_overrides or {}))
-    with mesh:
-        built = build_step(cfg, shape, rules)
-        lowered = built.fn.lower(*built.args_abs)
-        compiled = lowered.compile()
-        mem = compiled.memory_analysis()
-        cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):   # some jax versions: [dict]
-            cost = cost[0] if cost else {}
-        text = compiled.as_text()
+    built = build_step(cfg, shape, rules)
+    compiled = built.fn.lower(*built.args_abs).compile()
+    mem = compiled.memory_analysis()
+    cost = compiled.cost_analysis()
+    text = compiled.as_text()
 
     n_chips = 1
     for v in mesh.shape.values():

@@ -86,6 +86,19 @@ def abstract_state(cfg: ModelConfig, opt: adamw.AdamWConfig):
     return jax.eval_shape(lambda: adamw.init_state(params_abs, opt))
 
 
+def init_train_state(cfg: ModelConfig, rules: MeshRules, key,
+                     opt: adamw.AdamWConfig | None = None) -> dict:
+    """Random params and zero AdamW moments, created already sharded: one jit
+    whose ``out_shardings`` are ``rules.state_specs``, so no device ever
+    holds the whole state (full-width qwen2-1.5b state is ~15 GB)."""
+    opt = opt or make_optimizer(cfg)
+    state_sh = rules.named(rules.state_specs(abstract_state(cfg, opt)))
+    def init_train_state(key):
+        return adamw.init_state(api.init_params(key, cfg), opt)
+
+    return jax.jit(init_train_state, out_shardings=state_sh)(key)
+
+
 def build_train_step(cfg: ModelConfig, shape: ShapeConfig, rules: MeshRules,
                      transform=None) -> BuiltStep:
     opt = make_optimizer(cfg)

@@ -23,7 +23,7 @@ from repro.configs import registry  # noqa: E402
 from repro.data.pipeline import RunaheadLoader, synthetic_batch  # noqa: E402
 from repro.launch.mesh import make_host_mesh  # noqa: E402
 from repro.launch.steps import (abstract_state, build_train_step,  # noqa
-                                make_optimizer)
+                                init_train_state, make_optimizer)
 from repro.models import api  # noqa: E402
 from repro.models.types import ShapeConfig  # noqa: E402
 from repro.optim import adamw  # noqa: E402
@@ -53,9 +53,8 @@ def tiny_setup(mesh=None, arch=ARCH):
 def check_sharded_train_step_matches_single_device():
     cfg, mesh, rules, built, state, batch_fn = tiny_setup()
     batch = batch_fn(0)
-    with mesh:
-        new_state, metrics = built.fn(state, batch)
-        dist_loss = float(metrics["loss"])
+    new_state, metrics = built.fn(state, batch)
+    dist_loss = float(metrics["loss"])
     # single-device reference
     params = api.init_params(jax.random.key(0), cfg)
     ref_loss = float(api.train_loss(params, jax.tree.map(jnp.asarray, batch), cfg))
@@ -64,12 +63,23 @@ def check_sharded_train_step_matches_single_device():
     print("OK sharded==single", dist_loss, ref_loss)
 
 
+def check_sharded_init_matches_device_put():
+    """init_train_state creates the state already sharded; it must equal
+    the single-device init placed with device_put, bit for bit and spec for
+    spec."""
+    cfg, mesh, rules, built, state, batch_fn = tiny_setup()
+    sharded = init_train_state(cfg, rules, jax.random.key(0))
+    for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(sharded)):
+        assert a.sharding.spec == b.sharding.spec, (a.sharding, b.sharding)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    print("OK sharded init == device_put init")
+
+
 def check_checkpoint_roundtrip():
     cfg, mesh, rules, built, state, batch_fn = tiny_setup()
     with tempfile.TemporaryDirectory() as d:
         ck = Checkpointer(d)
-        with mesh:
-            state, _ = built.fn(state, batch_fn(0))
+        state, _ = built.fn(state, batch_fn(0))
         ck.save(1, state, blocking=True)
         abstract = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=x.sharding),
@@ -84,21 +94,20 @@ def check_crash_resume_bitwise():
     with tempfile.TemporaryDirectory() as d:
         cfg, mesh, rules, built, state0, batch_fn = tiny_setup()
         ck = Checkpointer(d)
-        with mesh:
-            driver = TrainDriver(built.fn, batch_fn, ck, checkpoint_every=3)
-            # uninterrupted run
-            ref_state, ref_hist = driver.run(state0, 8)
-            # crashed run from a fresh copy of the same init
-            _, _, _, _, state1, _ = tiny_setup(mesh)
-            try:
-                driver.run(state1, 8, fail_at=5)
-                raise AssertionError("failure not raised")
-            except SimulatedFailure:
-                pass
-            abstract = jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                               sharding=x.sharding), ref_state)
-            resumed_state, hist2 = driver.resume(abstract, 8)
+        driver = TrainDriver(built.fn, batch_fn, ck, checkpoint_every=3)
+        # uninterrupted run
+        ref_state, ref_hist = driver.run(state0, 8)
+        # crashed run from a fresh copy of the same init
+        _, _, _, _, state1, _ = tiny_setup(mesh)
+        try:
+            driver.run(state1, 8, fail_at=5)
+            raise AssertionError("failure not raised")
+        except SimulatedFailure:
+            pass
+        abstract = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=x.sharding), ref_state)
+        resumed_state, hist2 = driver.resume(abstract, 8)
         np.testing.assert_allclose(
             float(ref_hist[-1]["loss"]), float(hist2[-1]["loss"]), rtol=1e-6)
         for a, b in zip(jax.tree.leaves(ref_state),
@@ -109,16 +118,14 @@ def check_crash_resume_bitwise():
 
 def check_elastic_reshard():
     cfg, mesh, rules, built, state, batch_fn = tiny_setup()
-    with mesh:
-        state, m1 = built.fn(state, batch_fn(0))
-        loss_a = float(m1["loss"])
+    state, m1 = built.fn(state, batch_fn(0))
+    loss_a = float(m1["loss"])
     # new mesh shape (as after losing/gaining hosts)
     mesh2 = make_host_mesh(4, 2)
     rules2 = MeshRules(mesh2, sequence_parallel=False)
     state2 = reshard_state(jax.tree.map(np.asarray, state), rules2)
     built2 = build_train_step(cfg, SHAPE, rules2)
-    with mesh2:
-        _, m2 = built2.fn(state2, batch_fn(1))
+    _, m2 = built2.fn(state2, batch_fn(1))
     assert np.isfinite(float(m2["loss"]))
     print("OK elastic reshard", loss_a, float(m2["loss"]))
 
@@ -129,8 +136,7 @@ def check_reshard_roundtrip():
     downsize followed by a recovery to the original topology restores the
     exact state."""
     cfg, mesh, rules, built, state, batch_fn = tiny_setup()
-    with mesh:
-        state, _ = built.fn(state, batch_fn(0))
+    state, _ = built.fn(state, batch_fn(0))
     rules2 = MeshRules(make_host_mesh(4, 2), sequence_parallel=False)
     state_b = reshard_state(state, rules2)
     for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(state_b)):

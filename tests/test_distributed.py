@@ -9,6 +9,7 @@ SCRIPT = pathlib.Path(__file__).parent / "host_mesh_checks.py"
 
 CHECKS = [
     "sharded_train_step_matches_single_device",
+    "sharded_init_matches_device_put",
     "checkpoint_roundtrip",
     "crash_resume_bitwise",
     "elastic_reshard",
@@ -25,6 +26,7 @@ def test_host_mesh(check):
         [sys.executable, str(SCRIPT), check],
         capture_output=True, text=True, timeout=600,
         env={"XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+             "JAX_PLATFORMS": "cpu",
              "PYTHONPATH": str(SCRIPT.parents[1] / "src"),
              "PATH": "/usr/bin:/bin:/usr/local/bin"},
     )

@@ -1,7 +1,7 @@
 """Slow integration test: one production-mesh dry-run cell compiles.
 
 The full 10x4x2 grid runs via ``python -m repro.launch.dryrun --all
---mesh both`` (EXPERIMENTS.md §Dry-run); this test pins the machinery in CI.
+--mesh both``; this test pins the machinery in CI.
 Runs in a subprocess so the 512 placeholder devices never leak into the main
 pytest process.
 """
@@ -19,7 +19,7 @@ def test_one_dryrun_cell_compiles():
         [sys.executable, "-m", "repro.launch.dryrun",
          "--arch", "qwen2-1.5b", "--shape", "decode_32k", "--mesh", "pod"],
         capture_output=True, text=True, timeout=560,
-        env={"PYTHONPATH": str(root / "src"),
+        env={"PYTHONPATH": str(root / "src"), "JAX_PLATFORMS": "cpu",
              "PATH": "/usr/bin:/bin:/usr/local/bin"},
         cwd=str(root),
     )

@@ -1,5 +1,7 @@
-"""Per-kernel allclose sweeps: Pallas (interpret=True) vs pure-jnp oracles,
-across shapes and dtypes, plus hypothesis property tests on invariants."""
+"""Per-kernel allclose sweeps: Pallas (interpreted on the CPU backend) vs
+pure-jnp oracles, across shapes and dtypes, plus hypothesis property tests
+on invariants.  tests/test_tpu_compile.py compiles the same kernels for a
+TPU v5e."""
 import functools
 
 import jax
@@ -28,13 +30,15 @@ TOLS = {jnp.float32: dict(rtol=1e-5, atol=1e-5),
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("impl", ["runahead", "pipelined"])
+@pytest.mark.parametrize("block_rows", [pytest.param(8, id="runahead"),
+                                        pytest.param(16, id="runahead16")])
 @pytest.mark.parametrize("n,v,d", [(32, 128, 128), (64, 1024, 256)])
-def test_gather_matches_ref(impl, dtype, n, v, d):
+def test_gather_matches_ref(block_rows, dtype, n, v, d):
+    """Bitwise: every row, odd and even (bf16 rows share 32-bit words)."""
     rng = np.random.default_rng(0)
     table = jnp.asarray(rng.normal(size=(v, d)), dtype)
     idx = jnp.asarray(rng.integers(0, v, n), jnp.int32)
-    out = gr_ops.gather(table, idx, impl=impl)
+    out = gr_ops.gather(table, idx, block_rows=block_rows)
     np.testing.assert_array_equal(np.asarray(out),
                                   np.asarray(gr_ref.gather_ref(table, idx)))
 
@@ -62,6 +66,20 @@ def test_gather_bag_matches_ref(seed, fanin):
     ref = gr_ref.gather_bag_ref(table, idx, w)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("fanin", [2, 4])
+def test_gather_bag_matches_ref_dtypes(dtype, fanin):
+    rng = np.random.default_rng(fanin)
+    s, v, d = 16, 256, 128
+    table = jnp.asarray(rng.normal(size=(v, d)), dtype)
+    idx = jnp.asarray(rng.integers(0, v, (s, fanin)), jnp.int32)
+    w = jnp.asarray(rng.normal(size=(s, fanin)), jnp.float32)
+    out = gr_ops.gather_bag(table, idx, w)
+    ref = gr_ref.gather_bag_ref(table, idx, w)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), **TOLS[dtype])
 
 
 # ---------------------------------------------------------------------------

@@ -233,18 +233,17 @@ def test_unsupported_arch_rejected(setup):
 
 def test_engine_under_host_mesh(setup):
     # the engine's jitted steps accept sharding rules: activation
-    # constraints installed, run under a (1,1) host mesh
+    # constraints installed, sharded over a (1,1) host mesh (no mesh context)
     cfg, params = setup
     from repro.launch.mesh import make_host_mesh
     from repro.sharding.rules import MeshRules
 
     mesh = make_host_mesh(1, 1)
     rules = MeshRules(mesh)
-    with mesh:
-        eng = ServeEngine(cfg, params, slots=2, max_len=32, page_size=8,
-                          prefill_chunk=8, rules=rules)
-        r = eng.submit([1, 2, 3], max_new_tokens=4)
-        eng.run()
+    eng = ServeEngine(cfg, params, slots=2, max_len=32, page_size=8,
+                      prefill_chunk=8, rules=rules)
+    r = eng.submit([1, 2, 3], max_new_tokens=4)
+    eng.run()
     eng.assert_no_leaks()
     assert r.state is RequestState.FINISHED
     assert len(r.out_tokens) == 4
