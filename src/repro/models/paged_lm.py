@@ -29,6 +29,11 @@ Masked writes keep every step jit-compiled at a fixed shape: inactive
 decode slots and prefill padding divert their write to the reserved null
 page 0 (paged; rewriting the value already there) or the scratch row S
 (dense), so no step ever recompiles as the batch composition changes.
+
+Both steps name their parts with ``jax.named_scope`` (metadata only; the
+compiled arithmetic is the same), so a device trace can split a step's
+time: ``qkv``, ``kv_write``, ``kv_read``, ``attention``, ``o_proj``,
+``mlp``, ``lm_head`` and ``sample``.
 """
 from __future__ import annotations
 
@@ -148,8 +153,36 @@ def _write_paged(pool, pid, off, vals, mask):
     Masked-out rows are diverted to the null page by the caller and write
     back the value already there — colliding diverted writes therefore all
     carry identical data, keeping the scatter deterministic."""
-    cur = pool[pid, off]
-    return pool.at[pid, off].set(jnp.where(mask[:, None, None], vals, cur))
+    with jax.named_scope("kv_write"):
+        cur = pool[pid, off]
+        return pool.at[pid, off].set(jnp.where(mask[:, None, None], vals, cur))
+
+
+def _qkv(p, x, positions, cfg: ModelConfig):
+    """Projections and RoPE: x [B,T,D] at ``positions`` [B|1,1,T]."""
+    with jax.named_scope("qkv"):
+        q, k, v = layers._project_qkv(p, x, x, layers.attn_dims(cfg))
+        if cfg.rope_theta > 0:
+            q = layers.apply_rope(q, positions, cfg.rope_theta)
+            k = layers.apply_rope(k, positions, cfg.rope_theta)
+        return q, k, v
+
+
+def _read_pages(pool, table, b, dims):
+    """Gather the pages of ``table`` [b, P] from ``pool`` as [b,Hkv,S,Dh]."""
+    with jax.named_scope("kv_read"):
+        g = jnp.take(pool, table, axis=0)                # [b,P,page,Hkv,Dh]
+        return g.reshape(b, -1, dims.n_kv, dims.d_head).transpose(0, 2, 1, 3)
+
+
+def _attend(p, q, k_read, v_read, q_positions):
+    """Attention over the read cache, then the output projection."""
+    s_len = k_read.shape[2]
+    with jax.named_scope("attention"):
+        y = layers.cache_attention(q, k_read, v_read,
+                                   jnp.arange(s_len)[None, :], q_positions)
+    with jax.named_scope("o_proj"):
+        return layers._merge_heads(p, y)
 
 
 def _attn_decode(p, x, c, cache, active, cfg: ModelConfig, attn_read: str):
@@ -160,11 +193,7 @@ def _attn_decode(p, x, c, cache, active, cfg: ModelConfig, attn_read: str):
     dims = layers.attn_dims(cfg)
     lengths = cache["lengths"]
     b = x.shape[0]
-    q, k, v = layers._project_qkv(p, x, x, dims)
-    if cfg.rope_theta > 0:
-        pp = lengths[:, None, None]                      # [B,1,1]
-        q = layers.apply_rope(q, pp, cfg.rope_theta)
-        k = layers.apply_rope(k, pp, cfg.rope_theta)
+    q, k, v = _qkv(p, x, lengths[:, None, None], cfg)
     k_tok = k[:, :, 0, :]                                # [B,Hkv,Dh]
     v_tok = v[:, :, 0, :]
     b_ids = jnp.arange(b)
@@ -181,30 +210,32 @@ def _attn_decode(p, x, c, cache, active, cfg: ModelConfig, attn_read: str):
         if attn_read == "kernel":
             # the Pallas paged-attention call path: repeat KV pages to the
             # query head count (GQA: kv head = q head // rep, matching the
-            # repeat layout), lengths+1 counts the token just written
+            # repeat layout), lengths+1 counts the token just written.
+            # The repeat and the kernel, which reads every page of each
+            # slot's row and attends in the same pass, are the KV read of
+            # this path, as the gather is of the other
             from repro.kernels.paged_attention import ops as paged_ops
             rep = dims.rep
-            kpf = jnp.repeat(kp, rep, axis=2) if rep > 1 else kp
-            vpf = jnp.repeat(vp, rep, axis=2) if rep > 1 else vp
-            y = paged_ops.paged_attention(q[:, :, 0, :], kpf, vpf, table,
-                                          lengths + 1)[:, :, None, :]
-            return layers._merge_heads(p, y), new_c
-        g = jnp.take(kp, table, axis=0)                  # [B,P,page,Hkv,Dh]
-        k_read = g.reshape(b, -1, dims.n_kv, dims.d_head).transpose(0, 2, 1, 3)
-        g = jnp.take(vp, table, axis=0)
-        v_read = g.reshape(b, -1, dims.n_kv, dims.d_head).transpose(0, 2, 1, 3)
+            with jax.named_scope("kv_read"):
+                kpf = jnp.repeat(kp, rep, axis=2) if rep > 1 else kp
+                vpf = jnp.repeat(vp, rep, axis=2) if rep > 1 else vp
+                y = paged_ops.paged_attention(q[:, :, 0, :], kpf, vpf, table,
+                                              lengths + 1)[:, :, None, :]
+            with jax.named_scope("o_proj"):
+                return layers._merge_heads(p, y), new_c
+        k_read = _read_pages(kp, table, b, dims)
+        v_read = _read_pages(vp, table, b, dims)
     else:
         kc, vc = c["k"], c["v"]                          # [B,Hkv,S+1,Dh]
         s_pad = kc.shape[2] - 1
         s_idx = jnp.where(active, jnp.clip(lengths, 0, s_pad - 1), s_pad)
-        kc = kc.at[b_ids, :, s_idx, :].set(k_tok)
-        vc = vc.at[b_ids, :, s_idx, :].set(v_tok)
+        with jax.named_scope("kv_write"):
+            kc = kc.at[b_ids, :, s_idx, :].set(k_tok)
+            vc = vc.at[b_ids, :, s_idx, :].set(v_tok)
         new_c = {"k": kc, "v": vc}
-        k_read, v_read = kc[:, :, :s_pad, :], vc[:, :, :s_pad, :]
-    s_len = k_read.shape[2]
-    y = layers.cache_attention(q, k_read, v_read,
-                               jnp.arange(s_len)[None, :], lengths[:, None])
-    return layers._merge_heads(p, y), new_c
+        with jax.named_scope("kv_read"):
+            k_read, v_read = kc[:, :, :s_pad, :], vc[:, :, :s_pad, :]
+    return _attend(p, q, k_read, v_read, lengths[:, None]), new_c
 
 
 def _attn_prefill(p, x, c, cache, slot, positions, write_mask,
@@ -217,11 +248,7 @@ def _attn_prefill(p, x, c, cache, slot, positions, write_mask,
     prefill."""
     dims = layers.attn_dims(cfg)
     chunk = x.shape[1]
-    q, k, v = layers._project_qkv(p, x, x, dims)
-    if cfg.rope_theta > 0:
-        pp = positions[None, None, :]                    # [1,1,C]
-        q = layers.apply_rope(q, pp, cfg.rope_theta)
-        k = layers.apply_rope(k, pp, cfg.rope_theta)
+    q, k, v = _qkv(p, x, positions[None, None, :], cfg)
     k_tok = k[0].transpose(1, 0, 2)                      # [C,Hkv,Dh]
     v_tok = v[0].transpose(1, 0, 2)
     if "k_pages" in c:
@@ -234,22 +261,20 @@ def _attn_prefill(p, x, c, cache, slot, positions, write_mask,
         kp = _write_paged(kp, pid, off, k_tok, write_mask)
         vp = _write_paged(vp, pid, off, v_tok, write_mask)
         new_c = {"k_pages": kp, "v_pages": vp}
-        g = jnp.take(kp, table_row, axis=0)              # [P,page,Hkv,Dh]
-        k_read = g.reshape(1, -1, dims.n_kv, dims.d_head).transpose(0, 2, 1, 3)
-        g = jnp.take(vp, table_row, axis=0)
-        v_read = g.reshape(1, -1, dims.n_kv, dims.d_head).transpose(0, 2, 1, 3)
+        k_read = _read_pages(kp, table_row, 1, dims)
+        v_read = _read_pages(vp, table_row, 1, dims)
     else:
         kc, vc = c["k"], c["v"]                          # [B,Hkv,S+1,Dh]
         s_pad = kc.shape[2] - 1
         pos_w = jnp.where(write_mask, jnp.clip(positions, 0, s_pad - 1), s_pad)
-        k_row = kc[slot].at[:, pos_w, :].set(k[0])       # [Hkv,S+1,Dh]
-        v_row = vc[slot].at[:, pos_w, :].set(v[0])
-        new_c = {"k": kc.at[slot].set(k_row), "v": vc.at[slot].set(v_row)}
-        k_read, v_read = k_row[None, :, :s_pad, :], v_row[None, :, :s_pad, :]
-    s_len = k_read.shape[2]
-    y = layers.cache_attention(q, k_read, v_read,
-                               jnp.arange(s_len)[None, :], positions[None, :])
-    return layers._merge_heads(p, y), new_c
+        with jax.named_scope("kv_write"):
+            k_row = kc[slot].at[:, pos_w, :].set(k[0])   # [Hkv,S+1,Dh]
+            v_row = vc[slot].at[:, pos_w, :].set(v[0])
+            new_c = {"k": kc.at[slot].set(k_row), "v": vc.at[slot].set(v_row)}
+        with jax.named_scope("kv_read"):
+            k_read = k_row[None, :, :s_pad, :]
+            v_read = v_row[None, :, :s_pad, :]
+    return _attend(p, q, k_read, v_read, positions[None, :]), new_c
 
 
 # ---------------------------------------------------------------------------
@@ -262,10 +287,11 @@ def _block(p, x, c, attn_fn, cfg: ModelConfig, spec):
     x = x + h
     if spec.ffn != "none":
         h = layers.apply_norm(p["ffn_norm"], x, cfg)
-        if spec.ffn == "moe":
-            h, _ = moe.apply_moe(p["moe"], h, cfg)
-        else:
-            h = layers.apply_mlp(p["mlp"], h)
+        with jax.named_scope("mlp"):
+            if spec.ffn == "moe":
+                h, _ = moe.apply_moe(p["moe"], h, cfg)
+            else:
+                h = layers.apply_mlp(p["mlp"], h)
         x = x + h
     return x, new_c
 
@@ -299,12 +325,14 @@ def serve_decode_step(params, tokens, active, temps, key_data, cache,
     x, new_layers = jax.lax.scan(group_body, x,
                                  (params["groups"], cache["layers"]))
     x = layers.apply_norm(params["final_norm"], x, cfg)
-    logits = (x[:, 0, :] @ _lm_head(params, cfg)).astype(jnp.float32)
-    logits = sharding.constrain(logits, "decode_logits")
-    if sampling:
-        next_tokens = _sample(logits, temps, key_data)
-    else:
-        next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    with jax.named_scope("lm_head"):
+        logits = (x[:, 0, :] @ _lm_head(params, cfg)).astype(jnp.float32)
+        logits = sharding.constrain(logits, "decode_logits")
+    with jax.named_scope("sample"):
+        if sampling:
+            next_tokens = _sample(logits, temps, key_data)
+        else:
+            next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     new_cache = dict(cache)
     new_cache["layers"] = new_layers
     new_cache["lengths"] = cache["lengths"] + active.astype(jnp.int32)
@@ -350,11 +378,13 @@ def serve_prefill_chunk(params, tokens, n_valid, slot, temp, key_data, cache,
     x = layers.apply_norm(params["final_norm"], x, cfg)
     last = jax.lax.dynamic_index_in_dim(
         x[0], jnp.clip(n_valid - 1, 0, chunk - 1), 0, keepdims=False)
-    logits = (last @ _lm_head(params, cfg)).astype(jnp.float32)
-    if sampling:
-        token = _sample(logits, temp, key_data)
-    else:
-        token = jnp.argmax(logits).astype(jnp.int32)
+    with jax.named_scope("lm_head"):
+        logits = (last @ _lm_head(params, cfg)).astype(jnp.float32)
+    with jax.named_scope("sample"):
+        if sampling:
+            token = _sample(logits, temp, key_data)
+        else:
+            token = jnp.argmax(logits).astype(jnp.int32)
     new_cache = dict(cache)
     new_cache["layers"] = new_layers
     new_cache["lengths"] = lengths.at[slot].add(

@@ -23,6 +23,12 @@ at submit; pool pressure preempts the youngest sequence (re-queued, later
 re-prefilled, token stream resumed exactly); per-request deadlines cancel
 via the same retirement path as normal completion.
 
+Every phase of a step is a host span (:func:`repro.serve.metrics.span`) on
+the profiler's clock, nested in ``serve.step``: ``serve.schedule``,
+``serve.sync_cache``, ``serve.inputs``, ``serve.dispatch.decode`` /
+``serve.dispatch.prefill``, ``serve.fetch`` (the wait for the device),
+``serve.accept`` and ``serve.pool_check``; ``serve.submit`` wraps intake.
+
 Fault drills plug into the shared chaos layer
 (:mod:`repro.runtime.chaos`): a plan — passed as ``chaos=`` or resolved
 from ``REPRO_CHAOS`` — can reject admissions (``serve.backpressure``,
@@ -44,7 +50,7 @@ from repro.models.paged_lm import serve_geometry
 from repro.runtime import chaos as chaos_mod
 from repro.runtime.fault_tolerance import StragglerWatchdog
 
-from .metrics import EngineMetrics, RequestMetrics
+from .metrics import EngineMetrics, RequestMetrics, span
 from .paging import PagePool
 from .scheduler import (Request, RequestState, SamplingParams, Scheduler,
                         TERMINAL)
@@ -84,6 +90,9 @@ class ServeEngine:
         self.clock = clock
 
         self.pages_per_seq, _ = serve_geometry(max_len, page_size)
+        # KV pages a decode step reads: every read path (the gather, the
+        # kernel's grid, the dense rows) touches each slot's whole page row
+        self.pages_read_per_decode = slots * self.pages_per_seq
         if n_pages is None:
             n_pages = 1 + slots * self.pages_per_seq
         # the pool drives scheduling for BOTH backends (dense included), so
@@ -91,7 +100,7 @@ class ServeEngine:
         self.pool = PagePool(n_pages, page_size)
         self.sched = Scheduler(slots=slots, max_len=max_len, pool=self.pool,
                                prefill_chunk=prefill_chunk,
-                               max_queue=max_queue)
+                               max_queue=max_queue, clock=clock)
         self.steps = build_serve_engine_steps(
             cfg, slots=slots, max_len=max_len, backend=backend,
             page_size=page_size, n_pages=n_pages, attn_read=attn_read,
@@ -112,53 +121,55 @@ class ServeEngine:
         """Enqueue one request.  Raises :class:`Backpressure` when the
         queue is full; returns a FAILED request (never runnable) when the
         prompt + budget exceed cache capacity."""
-        now = self.clock() if arrival is None else arrival
-        req = Request(
-            rid=self._next_rid,
-            prompt=list(map(int, prompt)),
-            params=SamplingParams(temperature=temperature, seed=seed,
-                                  max_new_tokens=max_new_tokens,
-                                  stop_token=stop_token),
-            arrival=now,
-            deadline=None if timeout is None else now + timeout,
-            stream_cb=stream_cb,
-            metrics=RequestMetrics(submit_time=now),
-        )
-        self._next_rid += 1
-        if self.chaos is not None:
-            fault = self.chaos.fire("serve.backpressure", str(req.rid))
-            if fault is not None:
-                raise Backpressure(
-                    f"injected backpressure (chaos) for rid {req.rid}")
-        if not req.prompt:
-            req.state = RequestState.FAILED
-            req.error = "empty prompt"
-        else:
-            self.sched.submit(req)          # may raise Backpressure
-        if req.state is RequestState.FAILED:
-            self.finished.append(req)
-        else:
-            # eager admission: grab a free slot now so queue capacity only
-            # bounds genuinely *waiting* requests
-            self.sched.admit()
-            in_flight = len(self.sched.queue) + self.sched.occupancy()
-            self.metrics.peak_in_flight = max(self.metrics.peak_in_flight,
-                                              in_flight)
-        return req
+        with span("serve.submit", rid=self._next_rid):
+            now = self.clock() if arrival is None else arrival
+            req = Request(
+                rid=self._next_rid,
+                prompt=list(map(int, prompt)),
+                params=SamplingParams(temperature=temperature, seed=seed,
+                                      max_new_tokens=max_new_tokens,
+                                      stop_token=stop_token),
+                arrival=now,
+                deadline=None if timeout is None else now + timeout,
+                stream_cb=stream_cb,
+                metrics=RequestMetrics(submit_time=now),
+            )
+            self._next_rid += 1
+            if self.chaos is not None:
+                fault = self.chaos.fire("serve.backpressure", str(req.rid))
+                if fault is not None:
+                    raise Backpressure(
+                        f"injected backpressure (chaos) for rid {req.rid}")
+            if not req.prompt:
+                req.state = RequestState.FAILED
+                req.error = "empty prompt"
+            else:
+                self.sched.submit(req)          # may raise Backpressure
+            if req.state is RequestState.FAILED:
+                self.finished.append(req)
+            else:
+                # eager admission: grab a free slot now so queue capacity only
+                # bounds genuinely *waiting* requests
+                self.sched.admit()
+                in_flight = len(self.sched.queue) + self.sched.occupancy()
+                self.metrics.peak_in_flight = max(self.metrics.peak_in_flight,
+                                                  in_flight)
+            return req
 
     # -- device-state reconciliation ----------------------------------------
     def _sync_cache(self) -> None:
         """Rebuild device lengths / page table from host truth."""
-        lens = np.zeros((self.n_slots,), np.int32)
-        for r in self.sched.live():
-            lens[r.slot] = r.cache_len
-        self.cache["lengths"] = jnp.asarray(lens)
-        if self.backend == "paged":
-            table = np.zeros((self.n_slots, self.pages_per_seq), np.int32)
+        with span("serve.sync_cache"):
+            lens = np.zeros((self.n_slots,), np.int32)
             for r in self.sched.live():
-                owned = self.pool.owned(r.rid)
-                table[r.slot, :len(owned)] = owned
-            self.cache["page_table"] = jnp.asarray(table)
+                lens[r.slot] = r.cache_len
+            self.cache["lengths"] = jnp.asarray(lens)
+            if self.backend == "paged":
+                table = np.zeros((self.n_slots, self.pages_per_seq), np.int32)
+                for r in self.sched.live():
+                    owned = self.pool.owned(r.rid)
+                    table[r.slot, :len(owned)] = owned
+                self.cache["page_table"] = jnp.asarray(table)
 
     # -- lifecycle helpers ---------------------------------------------------
     def _retire(self, req: Request, state: RequestState, now: float,
@@ -187,7 +198,7 @@ class ServeEngine:
     def _scan_timeouts(self, now: float) -> None:
         for r in list(self.sched.queue):
             if r.deadline is not None and now >= r.deadline:
-                self.sched.queue.remove(r)
+                self.sched.dequeue(r)
                 self._retire(r, RequestState.CANCELLED, now, "timeout")
                 self.metrics.timeouts += 1
         for r in list(self.sched.live()):
@@ -199,24 +210,27 @@ class ServeEngine:
         if req.state in TERMINAL:
             return
         if req in self.sched.queue:
-            self.sched.queue.remove(req)
+            self.sched.dequeue(req)
         self._retire(req, RequestState.CANCELLED, self.clock(), reason)
 
     # -- the two step kinds --------------------------------------------------
     def _run_prefill(self, req: Request, now: float) -> None:
         toks = req.prefill_tokens
         n_valid = min(self.prefill_chunk, len(toks) - req.cache_len)
-        self.sched.ensure_pages(req, req.cache_len + n_valid)
+        with span("serve.schedule"):
+            self.sched.ensure_pages(req, req.cache_len + n_valid)
         if req.state is not RequestState.PREFILL:
             return                     # preempted itself under extreme pressure
-        chunk = np.zeros((self.prefill_chunk,), np.int32)
-        chunk[:n_valid] = toks[req.cache_len:req.cache_len + n_valid]
+        with span("serve.inputs"):
+            chunk = np.zeros((self.prefill_chunk,), np.int32)
+            chunk[:n_valid] = toks[req.cache_len:req.cache_len + n_valid]
+            key_data = _key_data(req.params.seed, len(req.out_tokens))
         req.metrics.on_admit(now)
         self._sync_cache()
-        token, logits, self.cache = self.steps.prefill(
-            self.params, chunk, np.int32(n_valid), np.int32(req.slot),
-            np.float32(req.params.temperature),
-            _key_data(req.params.seed, len(req.out_tokens)), self.cache)
+        with span("serve.dispatch.prefill", rid=req.rid):
+            token, logits, self.cache = self.steps.prefill(
+                self.params, chunk, np.int32(n_valid), np.int32(req.slot),
+                np.float32(req.params.temperature), key_data, self.cache)
         req.cache_len += n_valid
         if req.cache_len >= len(toks):             # final chunk
             req.state = RequestState.DECODE
@@ -225,63 +239,78 @@ class ServeEngine:
                 # discarded — the pre-preemption pending token carries on
                 req.pending_token = req.out_tokens[-1]
             else:
-                self._accept_token(req, int(token), logits, self.clock())
+                with span("serve.fetch"):
+                    token = int(token)
+                with span("serve.accept"):
+                    self._accept_token(req, token, logits, self.clock())
 
     def _run_decode(self, now: float) -> None:
-        for r in list(self.sched.live()):
-            if r.state is RequestState.DECODE:
-                self.sched.ensure_pages(r, r.cache_len + 1)
-        batch = [r for r in self.sched.live()
-                 if r.state is RequestState.DECODE]
+        with span("serve.schedule"):
+            for r in list(self.sched.live()):
+                if r.state is RequestState.DECODE:
+                    self.sched.ensure_pages(r, r.cache_len + 1)
+            batch = [r for r in self.sched.live()
+                     if r.state is RequestState.DECODE]
         if not batch:
             return
-        tokens = np.zeros((self.n_slots,), np.int32)
-        active = np.zeros((self.n_slots,), bool)
-        temps = np.zeros((self.n_slots,), np.float32)
-        key_data = np.zeros((self.n_slots, 2), np.uint32)
-        for r in batch:
-            tokens[r.slot] = r.pending_token
-            active[r.slot] = True
-            temps[r.slot] = r.params.temperature
-            key_data[r.slot] = _key_data(r.params.seed, len(r.out_tokens))
+        with span("serve.inputs"):
+            tokens = np.zeros((self.n_slots,), np.int32)
+            active = np.zeros((self.n_slots,), bool)
+            temps = np.zeros((self.n_slots,), np.float32)
+            key_data = np.zeros((self.n_slots, 2), np.uint32)
+            live = 0
+            for r in batch:
+                tokens[r.slot] = r.pending_token
+                active[r.slot] = True
+                temps[r.slot] = r.params.temperature
+                key_data[r.slot] = _key_data(r.params.seed, len(r.out_tokens))
+                live += self.pool.pages_for(r.cache_len + 1)
+        self.metrics.on_decode(live, self.pages_read_per_decode)
         self._sync_cache()
-        next_tokens, logits, self.cache = self.steps.decode(
-            self.params, tokens, active, temps, key_data, self.cache)
-        next_tokens = np.asarray(next_tokens)
-        done = self.clock()
-        for r in batch:
-            r.cache_len += 1
-            self._accept_token(
-                r, int(next_tokens[r.slot]),
-                None if logits is None else logits[r.slot], done)
+        with span("serve.dispatch.decode"):
+            next_tokens, logits, self.cache = self.steps.decode(
+                self.params, tokens, active, temps, key_data, self.cache)
+        with span("serve.fetch"):
+            next_tokens = np.asarray(next_tokens)
+        with span("serve.accept"):
+            done = self.clock()
+            for r in batch:
+                r.cache_len += 1
+                self._accept_token(
+                    r, int(next_tokens[r.slot]),
+                    None if logits is None else logits[r.slot], done)
 
     # -- main loop -----------------------------------------------------------
     def step(self) -> bool:
         """Run one engine step; returns False when there was nothing to do."""
-        now = self.clock()
-        self._scan_timeouts(now)
-        self.sched.admit()
-        action = self.sched.next_action()
-        if action.kind == "idle":
-            return False
-        t0 = time.monotonic()
-        if action.kind == "prefill":
-            self._run_prefill(action.request, now)
-        else:
-            self._run_decode(now)
-        dt = time.monotonic() - t0
-        if self.chaos is not None:
-            fault = self.chaos.fire("serve.step", str(self.metrics.steps))
-            if fault is not None and fault.kind == "delay":
-                dt += fault.seconds       # stretch the measured step time
-        if self.watchdog.record(self.metrics.steps, dt):
-            self.metrics.stragglers += 1
-        self.metrics.preemptions = self.sched.n_preemptions
-        self.metrics.on_step(action.kind,
-                             self.sched.occupancy() / self.n_slots,
-                             self.pool.utilization())
-        self.pool.check()
-        return True
+        with span("serve.step"):
+            with span("serve.schedule"):
+                now = self.clock()
+                self._scan_timeouts(now)
+                self.sched.admit(now)
+                action = self.sched.next_action()
+                backlog = self.sched.backlog_chunks()
+            if action.kind == "idle":
+                return False
+            t0 = time.monotonic()
+            if action.kind == "prefill":
+                self._run_prefill(action.request, now)
+            else:
+                self._run_decode(now)
+            dt = time.monotonic() - t0
+            if self.chaos is not None:
+                fault = self.chaos.fire("serve.step", str(self.metrics.steps))
+                if fault is not None and fault.kind == "delay":
+                    dt += fault.seconds       # stretch the measured step time
+            if self.watchdog.record(self.metrics.steps, dt):
+                self.metrics.stragglers += 1
+            self.metrics.preemptions = self.sched.n_preemptions
+            self.metrics.on_step(action.kind,
+                                 self.sched.occupancy() / self.n_slots,
+                                 self.pool.utilization(), backlog)
+            with span("serve.pool_check"):
+                self.pool.check()
+            return True
 
     def run(self, max_steps: Optional[int] = None) -> list[Request]:
         """Step until all submitted work is terminal; returns finished

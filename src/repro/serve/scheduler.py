@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import time
 from collections import deque
 from typing import Any, Callable, Optional
 
@@ -75,6 +76,11 @@ class Request:
         return self.prompt
 
     @property
+    def prefill_len(self) -> int:
+        """``len(prefill_tokens)``, without building the list."""
+        return len(self.prompt) + max(0, len(self.out_tokens) - 1)
+
+    @property
     def total_len(self) -> int:
         return len(self.prompt) + self.params.max_new_tokens
 
@@ -95,16 +101,21 @@ class Action:
 
 class Scheduler:
     def __init__(self, *, slots: int, max_len: int, pool: PagePool,
-                 prefill_chunk: int = 16, max_queue: int = 1024):
+                 prefill_chunk: int = 16, max_queue: int = 1024,
+                 clock: Callable[[], float] = time.monotonic):
         self.n_slots = slots
         self.max_len = max_len
         self.pool = pool
         self.prefill_chunk = prefill_chunk
         self.max_queue = max_queue
+        self.clock = clock            # stamps a request's slot time
         self.queue: deque[Request] = deque()
         self.slots: list[Optional[Request]] = [None] * slots
         self._last_kind = "decode"    # so the first mixed step prefers prefill
         self.n_preemptions = 0
+        # prefill chunks the queued requests owe, kept at every queue change
+        # so the backlog costs O(slots) per step, not a walk of the queue
+        self.queued_chunks = 0
 
     # -- bookkeeping ---------------------------------------------------------
     def live(self) -> list[Request]:
@@ -115,6 +126,25 @@ class Scheduler:
 
     def has_work(self) -> bool:
         return bool(self.queue) or self.occupancy() > 0
+
+    def _chunks(self, n_tokens: int) -> int:
+        return -(-n_tokens // self.prefill_chunk)
+
+    def backlog_chunks(self) -> int:
+        """Prefill chunks owed: by requests in a slot in prefill (what is
+        left of their prompt) and by queued ones (all of it)."""
+        return self.queued_chunks + sum(
+            self._chunks(r.prefill_len - r.cache_len) for r in self.slots
+            if r is not None and r.state is RequestState.PREFILL)
+
+    def _enqueue(self, req: Request, front: bool = False) -> None:
+        (self.queue.appendleft if front else self.queue.append)(req)
+        self.queued_chunks += self._chunks(req.prefill_len)
+
+    def dequeue(self, req: Request) -> None:
+        """Take a queued request out of the queue (timeout, cancel)."""
+        self.queue.remove(req)
+        self.queued_chunks -= self._chunks(req.prefill_len)
 
     def _free_slot(self) -> int:
         for i, r in enumerate(self.slots):
@@ -134,33 +164,38 @@ class Scheduler:
             req.error = (f"needs {req.total_len} tokens > capacity "
                          f"(max_len={self.max_len})")
             return
-        self.queue.append(req)
+        self._enqueue(req)
 
-    def admit(self) -> list[Request]:
+    def admit(self, now: Optional[float] = None) -> list[Request]:
         """Move queued requests into free slots, in FIFO order.
 
         A request is admitted only when a slot is free AND the pool can
         grant the pages for its first prefill chunk — admission never
         triggers preemption (only *growth* of already-running sequences
-        does, see :meth:`ensure_pages`)."""
+        does, see :meth:`ensure_pages`).  Each admitted request's metrics
+        get the slot time ``now`` (the scheduler's clock by default)."""
         admitted = []
         while self.queue:
             slot = self._free_slot()
             if slot < 0:
                 break
             req = self.queue[0]
-            first = min(self.prefill_chunk, len(req.prefill_tokens))
+            first = min(self.prefill_chunk, req.prefill_len)
             if self.pool.pages_for(first) > self.pool.free_pages:
                 break        # head-of-line blocks: FIFO, no bypass
-            self.queue.popleft()
+            self.dequeue(req)
             try:
                 self.pool.ensure(req.rid, first)
             except Exception:   # pragma: no cover - guarded above
-                self.queue.appendleft(req)
+                self._enqueue(req, front=True)
                 break
             req.slot = slot
             req.state = RequestState.PREFILL
             self.slots[slot] = req
+            if req.metrics is not None:
+                if now is None:
+                    now = self.clock()
+                req.metrics.on_slot(now)
             admitted.append(req)
         return admitted
 
@@ -191,7 +226,7 @@ class Scheduler:
         victim.cache_len = 0
         victim.preemptions += 1
         self.n_preemptions += 1
-        self.queue.appendleft(victim)
+        self._enqueue(victim, front=True)
         return victim
 
     def ensure_pages(self, req: Request, n_tokens: int) -> list[Request]:
