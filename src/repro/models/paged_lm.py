@@ -7,15 +7,24 @@ advance a *slot batch* — every slot is an independent sequence at its own
 depth, slots join and leave between steps, and the KV cache behind them is
 either
 
-* ``paged`` — a global physical page pool per layer
-  (``k_pages``/``v_pages``: ``[G, n_pages, page, Hkv, Dh]``) indirected
-  through a per-slot ``page_table`` ``[B, pages_per_seq]`` plus per-slot
-  ``lengths`` ``[B]`` — exactly the
-  :mod:`repro.kernels.paged_attention` operand layout, so the attention
-  read can run through that kernel (``attn_read="kernel"``); or
+* ``paged`` — one physical page pool per pattern entry, stacked over the
+  ``G`` layer groups (``k_pages``/``v_pages``: ``[G, n_pages, page, Hkv,
+  Dh]``) and indirected through a per-slot ``page_table`` ``[B,
+  pages_per_seq]`` plus per-slot ``lengths`` ``[B]``; a layer's pool
+  ``[g]`` is exactly the :mod:`repro.kernels.paged_attention` operand
+  layout, so the attention read can run through that kernel
+  (``attn_read="kernel"``); or
 * ``dense`` — per-slot contiguous KV ``[G, B, Hkv, S+1, Dh]`` (slot ``S``
   is a write-diversion scratch row), the oracle the paged path is tested
   bit-identical against.
+
+The stacked caches ride in the layer scan's carry, next to the
+activations, and each group indexes them at its own ``g``: the token's
+K/V is scattered into ``[g, page, offset]`` of the carried buffer and the
+read gathers ``[g, page_table]`` from it directly.  So a step updates the
+donated pool in place; passing the pools as the scan's ``xs``/``ys``
+instead would slice each layer's pool out, write it back into a second,
+freshly allocated stack and copy that stack into the result.
 
 Both backends run the *same* projection / RoPE / attention / FFN code with
 the same shapes; only where K/V bytes live differs.  Stale bytes in reused
@@ -146,16 +155,17 @@ def _sample(logits, temps, key_data):
 # per-layer attention with serve-cache read/write
 # ---------------------------------------------------------------------------
 
-def _write_paged(pool, pid, off, vals, mask):
-    """Masked scatter of per-token rows into a physical page pool.
+def _write_paged(pool, g, pid, off, vals, mask):
+    """Masked scatter of per-token rows into group ``g`` of a stacked pool.
 
-    pool [N, page, Hkv, Dh]; pid/off [T]; vals [T, Hkv, Dh]; mask [T].
+    pool [G, N, page, Hkv, Dh]; pid/off [T]; vals [T, Hkv, Dh]; mask [T].
     Masked-out rows are diverted to the null page by the caller and write
     back the value already there — colliding diverted writes therefore all
     carry identical data, keeping the scatter deterministic."""
     with jax.named_scope("kv_write"):
-        cur = pool[pid, off]
-        return pool.at[pid, off].set(jnp.where(mask[:, None, None], vals, cur))
+        cur = pool[g, pid, off]
+        return pool.at[g, pid, off].set(
+            jnp.where(mask[:, None, None], vals, cur))
 
 
 def _qkv(p, x, positions, cfg: ModelConfig):
@@ -168,11 +178,14 @@ def _qkv(p, x, positions, cfg: ModelConfig):
         return q, k, v
 
 
-def _read_pages(pool, table, b, dims):
-    """Gather the pages of ``table`` [b, P] from ``pool`` as [b,Hkv,S,Dh]."""
+def _read_pages(pool, g, table, b, dims):
+    """Gather the pages of ``table`` [b, P] (or [P] for b = 1) from group
+    ``g`` of the stacked ``pool`` as [b,Hkv,S,Dh], in one gather: no layer
+    slice of the pool is made first."""
     with jax.named_scope("kv_read"):
-        g = jnp.take(pool, table, axis=0)                # [b,P,page,Hkv,Dh]
-        return g.reshape(b, -1, dims.n_kv, dims.d_head).transpose(0, 2, 1, 3)
+        pages = pool[g, table]                           # [b,P,page,Hkv,Dh]
+        return pages.reshape(b, -1, dims.n_kv,
+                             dims.d_head).transpose(0, 2, 1, 3)
 
 
 def _attend(p, q, k_read, v_read, q_positions):
@@ -185,11 +198,13 @@ def _attend(p, q, k_read, v_read, q_positions):
         return layers._merge_heads(p, y)
 
 
-def _attn_decode(p, x, c, cache, active, cfg: ModelConfig, attn_read: str):
-    """One decode token per slot: x [B,1,D] -> (y [B,1,D], new layer cache).
+def _attn_decode(p, x, c, g, cache, active, cfg: ModelConfig,
+                 attn_read: str):
+    """One decode token per slot: x [B,1,D] -> (y [B,1,D], new cache ``c``).
 
-    ``c`` is this layer's cache slice (G-axis removed by the group scan);
-    ``cache`` provides the shared ``lengths`` / ``page_table``."""
+    ``c`` is this pattern entry's cache stacked over all groups (the scan's
+    carry); the layer reads and writes it at group ``g``.  ``cache``
+    provides the shared ``lengths`` / ``page_table``."""
     dims = layers.attn_dims(cfg)
     lengths = cache["lengths"]
     b = x.shape[0]
@@ -199,13 +214,13 @@ def _attn_decode(p, x, c, cache, active, cfg: ModelConfig, attn_read: str):
     b_ids = jnp.arange(b)
     if "k_pages" in c:
         kp, vp = c["k_pages"], c["v_pages"]
-        page = kp.shape[1]
+        page = kp.shape[2]
         table = cache["page_table"]
         lp = jnp.clip(lengths // page, 0, table.shape[1] - 1)
         pid = jnp.where(active, table[b_ids, lp], NULL_PAGE)
         off = jnp.where(active, lengths % page, 0)
-        kp = _write_paged(kp, pid, off, k_tok, active)
-        vp = _write_paged(vp, pid, off, v_tok, active)
+        kp = _write_paged(kp, g, pid, off, k_tok, active)
+        vp = _write_paged(vp, g, pid, off, v_tok, active)
         new_c = {"k_pages": kp, "v_pages": vp}
         if attn_read == "kernel":
             # the Pallas paged-attention call path: repeat KV pages to the
@@ -217,35 +232,37 @@ def _attn_decode(p, x, c, cache, active, cfg: ModelConfig, attn_read: str):
             from repro.kernels.paged_attention import ops as paged_ops
             rep = dims.rep
             with jax.named_scope("kv_read"):
-                kpf = jnp.repeat(kp, rep, axis=2) if rep > 1 else kp
-                vpf = jnp.repeat(vp, rep, axis=2) if rep > 1 else vp
+                kpf, vpf = kp[g], vp[g]
+                if rep > 1:
+                    kpf = jnp.repeat(kpf, rep, axis=2)
+                    vpf = jnp.repeat(vpf, rep, axis=2)
                 y = paged_ops.paged_attention(q[:, :, 0, :], kpf, vpf, table,
                                               lengths + 1)[:, :, None, :]
             with jax.named_scope("o_proj"):
                 return layers._merge_heads(p, y), new_c
-        k_read = _read_pages(kp, table, b, dims)
-        v_read = _read_pages(vp, table, b, dims)
+        k_read = _read_pages(kp, g, table, b, dims)
+        v_read = _read_pages(vp, g, table, b, dims)
     else:
-        kc, vc = c["k"], c["v"]                          # [B,Hkv,S+1,Dh]
-        s_pad = kc.shape[2] - 1
+        kc, vc = c["k"], c["v"]                          # [G,B,Hkv,S+1,Dh]
+        s_pad = kc.shape[3] - 1
         s_idx = jnp.where(active, jnp.clip(lengths, 0, s_pad - 1), s_pad)
         with jax.named_scope("kv_write"):
-            kc = kc.at[b_ids, :, s_idx, :].set(k_tok)
-            vc = vc.at[b_ids, :, s_idx, :].set(v_tok)
+            kc = kc.at[g, b_ids, :, s_idx, :].set(k_tok)
+            vc = vc.at[g, b_ids, :, s_idx, :].set(v_tok)
         new_c = {"k": kc, "v": vc}
         with jax.named_scope("kv_read"):
-            k_read, v_read = kc[:, :, :s_pad, :], vc[:, :, :s_pad, :]
+            k_read, v_read = kc[g, :, :, :s_pad, :], vc[g, :, :, :s_pad, :]
     return _attend(p, q, k_read, v_read, lengths[:, None]), new_c
 
 
-def _attn_prefill(p, x, c, cache, slot, positions, write_mask,
+def _attn_prefill(p, x, c, g, cache, slot, positions, write_mask,
                   cfg: ModelConfig):
-    """Prefill chunk for one slot: x [1,C,D] -> (y [1,C,D], new cache).
+    """Prefill chunk for one slot: x [1,C,D] -> (y [1,C,D], new cache ``c``).
 
     Writes the chunk's K/V into the slot's cache region, then attends the
     chunk queries over the slot's full cache (earlier chunks included), so
     chunked prefill is exact — not an approximation of whole-prompt
-    prefill."""
+    prefill.  ``c`` and ``g`` as in :func:`_attn_decode`."""
     dims = layers.attn_dims(cfg)
     chunk = x.shape[1]
     q, k, v = _qkv(p, x, positions[None, None, :], cfg)
@@ -253,27 +270,27 @@ def _attn_prefill(p, x, c, cache, slot, positions, write_mask,
     v_tok = v[0].transpose(1, 0, 2)
     if "k_pages" in c:
         kp, vp = c["k_pages"], c["v_pages"]
-        page = kp.shape[1]
+        page = kp.shape[2]
         table_row = cache["page_table"][slot]            # [P]
         lp = jnp.clip(positions // page, 0, table_row.shape[0] - 1)
         pid = jnp.where(write_mask, table_row[lp], NULL_PAGE)
         off = jnp.where(write_mask, positions % page, jnp.arange(chunk) % page)
-        kp = _write_paged(kp, pid, off, k_tok, write_mask)
-        vp = _write_paged(vp, pid, off, v_tok, write_mask)
+        kp = _write_paged(kp, g, pid, off, k_tok, write_mask)
+        vp = _write_paged(vp, g, pid, off, v_tok, write_mask)
         new_c = {"k_pages": kp, "v_pages": vp}
-        k_read = _read_pages(kp, table_row, 1, dims)
-        v_read = _read_pages(vp, table_row, 1, dims)
+        k_read = _read_pages(kp, g, table_row, 1, dims)
+        v_read = _read_pages(vp, g, table_row, 1, dims)
     else:
-        kc, vc = c["k"], c["v"]                          # [B,Hkv,S+1,Dh]
-        s_pad = kc.shape[2] - 1
+        kc, vc = c["k"], c["v"]                          # [G,B,Hkv,S+1,Dh]
+        s_pad = kc.shape[3] - 1
         pos_w = jnp.where(write_mask, jnp.clip(positions, 0, s_pad - 1), s_pad)
         with jax.named_scope("kv_write"):
-            k_row = kc[slot].at[:, pos_w, :].set(k[0])   # [Hkv,S+1,Dh]
-            v_row = vc[slot].at[:, pos_w, :].set(v[0])
-            new_c = {"k": kc.at[slot].set(k_row), "v": vc.at[slot].set(v_row)}
+            kc = kc.at[g, slot, :, pos_w, :].set(k_tok)
+            vc = vc.at[g, slot, :, pos_w, :].set(v_tok)
+        new_c = {"k": kc, "v": vc}
         with jax.named_scope("kv_read"):
-            k_read = k_row[None, :, :s_pad, :]
-            v_read = v_row[None, :, :s_pad, :]
+            k_read = kc[g, slot, :, :s_pad, :][None]
+            v_read = vc[g, slot, :, :s_pad, :][None]
     return _attend(p, q, k_read, v_read, positions[None, :]), new_c
 
 
@@ -296,6 +313,30 @@ def _block(p, x, c, attn_fn, cfg: ModelConfig, spec):
     return x, new_c
 
 
+def _scan_groups(params, x, caches, attn_fn, cfg: ModelConfig):
+    """Run the layer groups over ``x`` -> (x, new ``caches``).
+
+    The weights are the scan's ``xs``, with each group's index ``g``; the
+    stacked caches (one per pattern entry) ride in its carry, so every
+    layer updates them in place through ``attn_fn(p, h, c, g)``."""
+    pattern = cfg.pattern()
+
+    def group_body(carry, inp):
+        x, caches = carry
+        group_params, g = inp
+        caches = list(caches)
+        for p_idx, spec in enumerate(pattern):
+            x, caches[p_idx] = _block(
+                group_params[p_idx], x, caches[p_idx],
+                lambda pa, h, c: attn_fn(pa, h, c, g), cfg, spec)
+        return (x, tuple(caches)), None
+
+    (x, caches), _ = jax.lax.scan(
+        group_body, (x, caches),
+        (params["groups"], jnp.arange(cfg.n_groups, dtype=jnp.int32)))
+    return x, caches
+
+
 def serve_decode_step(params, tokens, active, temps, key_data, cache,
                       cfg: ModelConfig, *, attn_read: str = "gather",
                       sampling: bool = True, return_logits: bool = False):
@@ -307,23 +348,12 @@ def serve_decode_step(params, tokens, active, temps, key_data, cache,
     write-diverted and their outputs are garbage the host ignores.
     Returns ``(next_tokens [B], logits [B,V] | None, new cache)``.
     """
-    pattern = cfg.pattern()
     x = jnp.take(params["embed"], tokens[:, None], axis=0)     # [B,1,D]
 
-    def attn_fn(pa, h, cc):
-        return _attn_decode(pa, h, cc, cache, active, cfg, attn_read)
+    def attn_fn(pa, h, c, g):
+        return _attn_decode(pa, h, c, g, cache, active, cfg, attn_read)
 
-    def group_body(x, inp):
-        group_params, group_cache = inp
-        new_caches = []
-        for p_idx, spec in enumerate(pattern):
-            x, new_c = _block(group_params[p_idx], x, group_cache[p_idx],
-                              attn_fn, cfg, spec)
-            new_caches.append(new_c)
-        return x, tuple(new_caches)
-
-    x, new_layers = jax.lax.scan(group_body, x,
-                                 (params["groups"], cache["layers"]))
+    x, new_layers = _scan_groups(params, x, cache["layers"], attn_fn, cfg)
     x = layers.apply_norm(params["final_norm"], x, cfg)
     with jax.named_scope("lm_head"):
         logits = (x[:, 0, :] @ _lm_head(params, cfg)).astype(jnp.float32)
@@ -352,7 +382,6 @@ def serve_prefill_chunk(params, tokens, n_valid, slot, temp, key_data, cache,
     new cache)`` where the sample is drawn from the last valid position's
     logits (only meaningful on the final chunk of a prompt).
     """
-    pattern = cfg.pattern()
     chunk = tokens.shape[0]
     lengths = cache["lengths"]
     start = lengths[slot]
@@ -360,21 +389,11 @@ def serve_prefill_chunk(params, tokens, n_valid, slot, temp, key_data, cache,
     write_mask = jnp.arange(chunk) < n_valid
     x = jnp.take(params["embed"], tokens[None, :], axis=0)     # [1,C,D]
 
-    def attn_fn(pa, h, cc):
-        return _attn_prefill(pa, h, cc, cache, slot, positions, write_mask,
+    def attn_fn(pa, h, c, g):
+        return _attn_prefill(pa, h, c, g, cache, slot, positions, write_mask,
                              cfg)
 
-    def group_body(x, inp):
-        group_params, group_cache = inp
-        new_caches = []
-        for p_idx, spec in enumerate(pattern):
-            x, new_c = _block(group_params[p_idx], x, group_cache[p_idx],
-                              attn_fn, cfg, spec)
-            new_caches.append(new_c)
-        return x, tuple(new_caches)
-
-    x, new_layers = jax.lax.scan(group_body, x,
-                                 (params["groups"], cache["layers"]))
+    x, new_layers = _scan_groups(params, x, cache["layers"], attn_fn, cfg)
     x = layers.apply_norm(params["final_norm"], x, cfg)
     last = jax.lax.dynamic_index_in_dim(
         x[0], jnp.clip(n_valid - 1, 0, chunk - 1), 0, keepdims=False)

@@ -1,4 +1,5 @@
-"""The main path's Pallas kernels compile for a TPU v5e, at real widths.
+"""The main path's Pallas kernels compile for a TPU v5e, at real widths,
+and the serve programs update their page pool in place there.
 
 No chip is needed: the TPU compiler is installed with JAX and compiles for a
 described ``v5e:2x2`` topology.  This catches what the CPU interpreter
@@ -10,7 +11,9 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +23,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import registry
 from repro.kernels.gather_runahead import gather_runahead as gr
 from repro.kernels.paged_attention import paged_attention as pa
+from repro.launch.steps import build_serve_engine_steps
+from repro.models import api
 
 QWEN = registry.get("qwen2-1.5b")
 
@@ -95,3 +100,88 @@ def test_paged_attention_compiles_at_qwen2_decode(one_chip):
         _spec(one_chip, pool, jnp.bfloat16),
         _spec(one_chip, (b, pps), jnp.int32), _spec(one_chip, (b,), jnp.int32))
     assert "tpu_custom_call" in hlo
+
+
+# qwen2-1.5b's widths, 3 layers: each stacked K or V pool is 47 MiB, too
+# large for on-chip memory, so a second stack would count as temporaries
+SERVE = dict(groups=3, slots=16, max_len=2048, chunk=256)
+
+
+def _result_type_and_opcode(rest):
+    """Split an HLO instruction's right-hand side into (type, opcode)."""
+    if rest.startswith("("):                      # a tuple: balanced parens
+        depth = 0
+        for i, ch in enumerate(rest):
+            depth += {"(": 1, ")": -1}.get(ch, 0)
+            if depth == 0:
+                break
+        typ, rest = rest[:i + 1], rest[i + 1:]
+    else:
+        typ, _, rest = rest.partition(" ")
+    return typ, rest.strip().partition("(")[0]
+
+
+MOVES = ("dynamic-slice", "dynamic-update-slice", "copy", "copy-start",
+         "copy-done")
+
+
+def _pool_traffic(hlo, stack, layer):
+    """Instructions that slice, write back, copy or allocate a pool:
+    a dynamic-slice, dynamic-update-slice or copy (by opcode or by the
+    fusion named for one) whose result is one layer's pool or the stacked
+    pool, and any buffer allocated at the stacked pool's shape."""
+    shapes = (f"bf16[{stack}]", f"bf16[{layer}]")
+    found = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT\s+)?%([\w.\-]+) = (.*)", line)
+        if not m:
+            continue
+        name, (typ, opcode) = m.group(1), _result_type_and_opcode(m.group(2))
+        if not any(sh in typ for sh in shapes):
+            continue
+        moves = (opcode in MOVES
+                 or re.search(r"dynamic[-_](update[-_])?slice", name))
+        allocates = "AllocateBuffer" in line and shapes[0] in typ
+        if moves or allocates:
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.fixture(scope="module")
+def serve_programs(one_chip):
+    cfg = dataclasses.replace(QWEN, n_layers=SERVE["groups"])
+    steps = build_serve_engine_steps(cfg, slots=SERVE["slots"],
+                                     max_len=SERVE["max_len"])
+    def on_chip(tree):
+        return jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+
+    def arg(shape, dtype):
+        return _spec(one_chip, shape, dtype)
+
+    params = on_chip(api.abstract_params(cfg))
+    cache = on_chip(steps.cache_abs)
+    b, c = SERVE["slots"], SERVE["chunk"]
+    args = {
+        "decode": (params, arg((b,), jnp.int32), arg((b,), jnp.bool_),
+                   arg((b,), jnp.float32), arg((b, 2), jnp.uint32), cache),
+        "prefill": (params, arg((c,), jnp.int32), arg((), jnp.int32),
+                    arg((), jnp.int32), arg((), jnp.float32),
+                    arg((2,), jnp.uint32), cache),
+    }
+    pool = steps.cache_abs["layers"][0]["k_pages"]
+    return pool, {name: getattr(steps, name).lower(*a).compile()
+                  for name, a in args.items()}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_serve_program_updates_pool_in_place(serve_programs, program):
+    """The layer scan carries the stacked pool: no layer's pool is sliced
+    out or written back, no second stack is allocated or copied back, and
+    the program's temporaries stay under one stacked pool."""
+    pool, compiled = serve_programs
+    stack = ",".join(map(str, pool.shape))
+    layer = ",".join(map(str, pool.shape[1:]))
+    exe = compiled[program]
+    assert _pool_traffic(exe.as_text(), stack, layer) == []
+    pool_bytes = pool.size * pool.dtype.itemsize
+    assert exe.memory_analysis().temp_size_in_bytes < pool_bytes
