@@ -48,3 +48,7 @@ def main() -> int:
             row["control"] = {"correct": check.passed(ctl), "checks": ctl}
         print(json.dumps(row), flush=True)
     return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

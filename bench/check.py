@@ -4,12 +4,16 @@ Once the window has closed and the program's state is freed, a sample of
 the greedy requests that were served tokens is drawn from the seed, the
 longest always in it, until it holds ``min_tokens`` served tokens.  A
 request still running at the close is compared on the tokens it was served
-(in the long-document cell most requests outlast the window).  The
-reference (``bench/reference``) runs once over each prompt followed by its
-served tokens, at the cell's ``max_len`` (one compiled shape), and the
-numbers compared are the widest gap by which a served token's logit lies
-below the reference's best logit at that position (``logit_gap``) and,
-where the cell's limits name it, the mean of those gaps (``mean_gap``).  A
+(in the long-document cell most requests outlast the window); after a
+window too short for ``min_tokens`` the engine serves on past the close,
+a minute at most (``bench.serving.Loop``).  The
+reference, the module that the configuration names under ``"reference"``
+(``bench/reference``; it provides ``BLOCK`` and ``logit_stats``), runs
+once over each prompt followed by its served tokens, at the cell's
+``max_len`` (one compiled shape), and the numbers compared are the widest
+gap by which a served token's logit lies below the reference's best logit
+at that position (``logit_gap``) and, where the cell's limits name it, the
+mean of those gaps (``mean_gap``).  A
 greedy engine that serves what the model says reads near 0 (rounding in
 bfloat16 can pick a near-tied runner-up); a wrong token reads about the
 spread of the logits.
@@ -23,8 +27,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from bench import weights
-from bench.reference import dense_decoder
+from bench import harness, weights
 
 
 def sample(served, seed: int, min_tokens: int) -> list:
@@ -56,9 +59,8 @@ def _inputs(prompt, served, length: int):
     return x, tgt
 
 
-def _length(max_len: int) -> int:
-    b = dense_decoder.BLOCK
-    return -(-max_len // b) * b
+def _length(max_len: int, block: int) -> int:
+    return -(-max_len // block) * block
 
 
 def gaps(cfg: dict, seed: int, reqs, max_len: int,
@@ -67,17 +69,18 @@ def gaps(cfg: dict, seed: int, reqs, max_len: int,
     the reference's best logit.  Without ``quant`` the token is the one
     served; with it, the one that the reference computed in ``quant``
     puts first (the control)."""
+    ref = harness.reference(cfg)
     w = weights.make(cfg, seed)
     out = []
     for prompt, served in reqs:
         if np.any((served < 0) | (served >= cfg["vocab_size"])):
             return np.array([np.inf])
-        x, tgt = _inputs(prompt, served, _length(max_len))
+        x, tgt = _inputs(prompt, served, _length(max_len, ref.BLOCK))
         sel = tgt >= 0
         if quant is not None:
-            _, _, top = dense_decoder.logit_stats(w, x, tgt, cfg, quant)
+            _, _, top = ref.logit_stats(w, x, tgt, cfg, quant)
             tgt = np.where(sel, top, -1)
-        mx, at, _ = dense_decoder.logit_stats(w, x, tgt, cfg)
+        mx, at, _ = ref.logit_stats(w, x, tgt, cfg)
         out.append(mx[sel] - at[sel])
     return np.concatenate(out) if out else np.array([np.inf])
 

@@ -3,7 +3,11 @@
 Everything that belongs to one configuration, traffic mix, cell or metric
 is a file found by its name, so a cell or metric is added by adding files:
 
-  bench/configs/<config>.json   published config.json keys + serve geometry
+  bench/configs/<config>.json   published config.json keys + serve geometry,
+                                and the paths of two modules: ``arch``
+                                (``bench/archs/dense_decoder.py`` says what
+                                it provides) and ``reference`` (``BLOCK``
+                                and ``logit_stats``, ``bench.check``)
   bench/traffic/<traffic>.json  the mix's parameters (``bench.loadgen``)
   bench/limits/<workload>.json  the limits of the correctness check
   bench/metrics/<metric>.py     ``read(record) -> number | None``
@@ -15,12 +19,14 @@ its parts: :func:`resolve`, :func:`window` and :func:`result`.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import gc
 import importlib
 import importlib.util
 import json
 import os
 import pathlib
+import re
 import sys
 import time
 
@@ -49,6 +55,7 @@ class Context:
     t_start: float
     compiles: object = None      # CompileCount
     hook: object = None          # tests only: called with the engine
+    min_tokens: int = 0          # greedy tokens served that the check needs
 
 
 @dataclasses.dataclass
@@ -78,6 +85,42 @@ def reader(name: str):
     return mod.read
 
 
+MODULE_KEYS = ("arch", "reference")
+
+
+def load_config(path: pathlib.Path) -> dict:
+    """A configuration file; it has to name its two modules."""
+    cfg = load_json(path)
+    missing = [k for k in MODULE_KEYS if k not in cfg]
+    if missing:
+        raise SystemExit(f"configuration {path} names no "
+                         f"{' and no '.join(map(repr, missing))} module")
+    return cfg
+
+
+@functools.cache
+def module_at(path: str):
+    """The module in the file at ``path``, from the repository's root
+    unless absolute; loaded once a process, so that what it compiles is
+    compiled once."""
+    full = REPO / path
+    name = "bench_module_" + re.sub(r"\W", "_", str(full))
+    spec = importlib.util.spec_from_file_location(name, full)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def arch(cfg: dict):
+    """The configuration's architecture module (``cfg["arch"]``)."""
+    return module_at(cfg["arch"])
+
+
+def reference(cfg: dict):
+    """The configuration's plain reference (``cfg["reference"]``)."""
+    return module_at(cfg["reference"])
+
+
 def cell_metrics(bench: dict, workload: str, trace: bool) -> list:
     """The cell's end-to-end metrics, or with ``trace`` its per-layer ones."""
     kind = "per_layer" if trace else "end_to_end"
@@ -90,7 +133,7 @@ def resolve(workload: str, bench: dict | None = None,
     bench = bench or load_json(REPO / "BENCHMARK.json")
     wl = find(bench["workloads"], workload, "workload")
     return Cell(name=workload, chips=wl["chips"], bench=bench,
-                cfg=load_json(data / "configs" / f"{wl['config']}.json"),
+                cfg=load_config(data / "configs" / f"{wl['config']}.json"),
                 mix=load_json(data / "traffic" / f"{wl['traffic']}.json"),
                 limits=load_json(data / "limits" / f"{workload}.json"))
 
@@ -151,7 +194,7 @@ def window(cell: Cell, seed: int, seconds: float, trace: bool, *,
     before it returns, so the reference runs on a free chip."""
     ctx = Context(cfg=cell.cfg, mix=cell.mix, seed=seed, seconds=seconds,
                   trace=trace, t_start=t_start, compiles=CompileCount(),
-                  hook=hook)
+                  hook=hook, min_tokens=cell.limits["min_tokens"])
     arrivals = importlib.import_module(f"bench.arrivals.{cell.mix['arrivals']}")
     loop = arrivals.run(ctx)
     out = Outcome(record=loop.record, served=loop.served_greedy(),
@@ -228,13 +271,18 @@ def load(rec: dict) -> dict:
         n_out = sum(r["max_new"] for r in due) / len(due)
         life = sum(first) / len(first) + (n_out - 1) * sum(gaps) / len(gaps)
         little = len(due) / w * life
+    sampled = sum(1 for r in rec["requests"] for t in r["tokens"]
+                  if 0.0 <= t < w)
     return {"due": len(due), "in_system_at_open": rec["in_system_at_open"],
+            "prompt_tokens_in_window": rec["prompt_tokens"],
+            "sampled_tokens_in_window": sampled,
             "little_in_system": little,
             "queued_at_mid": rec["queue_at_mid"],
             "queued_at_close": rec["queue"],
             "busy_slots_at_close": rec["occupancy"],
             "generator_lag_s": rec["lag_s"],
-            "compilations_in_window": rec["compiles"]}
+            "compilations_in_window": rec["compiles"],
+            "served_past_close_s": rec["past_close_s"]}
 
 
 def run(workload: str, seed: int, seconds: float, trace: bool, *,

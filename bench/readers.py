@@ -7,7 +7,7 @@ is left out of the result line.
 """
 from __future__ import annotations
 
-from bench import counts, peaks, trace_reduce
+from bench import harness, peaks, trace_reduce
 
 
 def percentile(xs, q: float):
@@ -49,6 +49,14 @@ def itl_p95_ms(rec):
             for a, b in zip(r["tokens"], r["tokens"][1:]) if a >= 0 and b <= w]
     p = percentile(gaps, 95)
     return None if p is None else p * 1e3
+
+
+def total_tok_s(rec):
+    """Every prompt token prefilled and every token sampled inside the
+    window, of any request, over the window's length."""
+    w = rec["window_s"]
+    n = sum(1 for r in rec["requests"] for t in r["tokens"] if 0.0 <= t < w)
+    return (rec["prompt_tokens"] + n) / w
 
 
 def setup_s(rec):
@@ -116,8 +124,9 @@ def decode_roofline(rec):
     if not t or not steps:
         return None
     cfg, pk = rec["config"], peaks.lookup(rec["device_kind"])
-    need = _mean([counts.decode_bytes(cfg, s.lengths) for s in steps])
-    fl = _mean([counts.decode_flops(cfg, s.lengths) for s in steps])
+    arch = harness.arch(cfg)
+    need = _mean([arch.decode_bytes(cfg, s.lengths) for s in steps])
+    fl = _mean([arch.decode_flops(cfg, s.lengths) for s in steps])
     least = max(need / pk["hbm_bytes_s"], fl / pk["bf16_flops_s"])
     return 100.0 * least / t
 
@@ -127,5 +136,6 @@ def decode_mfu(rec):
     if not t or not steps:
         return None
     cfg, pk = rec["config"], peaks.lookup(rec["device_kind"])
-    fl = _mean([counts.decode_flops(cfg, s.lengths) for s in steps])
+    fl = _mean([harness.arch(cfg).decode_flops(cfg, s.lengths)
+                for s in steps])
     return 100.0 * fl / pk["bf16_flops_s"] / t

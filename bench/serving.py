@@ -1,12 +1,13 @@
 """One measured window of the serving engine, shared by the arrival modules.
 
 Set-up builds the weights (``bench.weights``, one jit), the program's
-``ServeEngine`` at the configuration's geometry, and compiles its two step
-programs by serving one request of two prefill chunks.  Then the mix's
-traffic runs for ``warmup_s`` (set-up: it fills the slots to a steady
-occupancy) and the window opens for ``seconds``.  An arrival module
-(``bench/arrivals``) decides only when requests are submitted
-(:class:`Loop` ``feed``).
+``ServeEngine`` for the ``ModelConfig`` that the configuration's
+architecture module gives (``program_config``) at the configuration's
+geometry, and compiles its two step programs by serving one request of two
+prefill chunks.  Then the mix's traffic runs for ``warmup_s`` (set-up: it
+fills the slots to a steady occupancy) and the window opens for
+``seconds``.  An arrival module (``bench/arrivals``) decides only when
+requests are submitted (:class:`Loop` ``feed``).
 
 A request is timed from its due time: the engine is given it as
 ``arrival``, so queue wait and time to first token include any lateness of
@@ -17,8 +18,8 @@ JAX profiler (Python tracer off), with host spans from this file:
 ``bench.window`` around the window, ``bench.engine_step`` around each
 ``engine.step()``, ``bench.call.decode`` / ``bench.call.prefill`` around
 the engine's calls of its jitted steps, and ``bench.wait`` while idle.  Each
-engine step is also recorded with the live lengths it ran over, for
-``bench.counts``.
+engine step is also recorded with the live lengths it ran over, for the
+architecture module's counts.
 """
 from __future__ import annotations
 
@@ -32,25 +33,11 @@ import time
 import jax
 import numpy as np
 
-from bench import trace_reduce, weights
+from bench import harness, trace_reduce, weights
 
 TRACE_S = 10.0
+PAST_CLOSE_S = 60.0    # longest the check waits for served tokens
 WARM_TOKENS = 2        # tokens of the compile warm-up request
-
-
-def model_config(cfg: dict):
-    """The program's ``ModelConfig`` for a published configuration file."""
-    from repro.models.types import ModelConfig
-    return ModelConfig(
-        name=cfg["name"], family="dense",
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        d_head=cfg["hidden_size"] // cfg["num_attention_heads"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        qkv_bias=cfg["qkv_bias"], tie_embeddings=cfg["tie_word_embeddings"],
-        rope_theta=float(cfg["rope_theta"]), norm_eps=cfg["rms_norm_eps"],
-        dtype="bfloat16", source=cfg["source"])
 
 
 def check_layout(params, mcfg) -> None:
@@ -79,7 +66,7 @@ class Loop:
         from repro.serve import ServeEngine
         self.ctx = ctx
         geo = ctx.cfg["serve"]
-        self.mcfg = model_config(ctx.cfg)
+        self.mcfg = harness.arch(ctx.cfg).program_config(ctx.cfg)
         self.params = weights.make(ctx.cfg, ctx.seed)
         check_layout(self.params, self.mcfg)
         self.eng = ServeEngine(
@@ -172,6 +159,7 @@ class Loop:
                     setup_s = time.time() - ctx.t_start
                     n_compiles = ctx.compiles.n
                     self.in_system_at_open = self._in_system()
+                    prompt_at_open = self._prompt_tokens()
                     if tracer:
                         tracer.open_window()
                 elif tracer and now >= w0 - 1.0:
@@ -185,12 +173,39 @@ class Loop:
                 nxt = min(self.feed.next_due(w0), w1 if setup_s else w0)
                 with _span(trace, "bench.wait"):
                     time.sleep(max(0.0, nxt - time.monotonic()))
+        prompt_in_window = self._prompt_tokens() - prompt_at_open
         jax.block_until_ready(self.eng.cache)
         n_compiles = ctx.compiles.n - n_compiles
         summary = tracer.stop() if tracer else None
         self.record = self._record(setup_s, seconds, summary)
         self.record["compiles"] = n_compiles
+        self.record["prompt_tokens"] = prompt_in_window
+        self.record["past_close_s"] = self._serve_past_close(ctx.min_tokens)
         return self.record
+
+    def _serve_past_close(self, min_tokens: int) -> float:
+        """Step on past the window's close, submitting nothing, until the
+        greedy requests hold ``min_tokens`` served tokens or a minute has
+        passed, so that the check has as many tokens to compare after a
+        traced window (10 s) as after a full one: in a long-document cell
+        few answers are far along 10 s after they start.  The metrics are
+        the record's, taken at the close.  Returns the seconds served."""
+        t0 = time.monotonic()
+        while (self._greedy_tokens() < min_tokens
+               and time.monotonic() - t0 < PAST_CLOSE_S and self.eng.step()):
+            pass
+        jax.block_until_ready(self.eng.cache)
+        return time.monotonic() - t0
+
+    def _prompt_tokens(self) -> int:
+        """Prompt tokens prefilled so far, over every request (one that
+        is preempted starts again from none: the pool of a cell is sized so
+        that none is)."""
+        return sum(min(r.cache_len, len(r.prompt)) for r in self.requests)
+
+    def _greedy_tokens(self) -> int:
+        return sum(len(r.out_tokens) for r in self.requests
+                   if r.params.temperature == 0.0)
 
     def _record(self, setup_s, seconds, summary) -> dict:
         w0, w1 = self.w0, self.w1
@@ -220,9 +235,10 @@ class Loop:
         return len(self.eng.sched.queue) + self.eng.sched.occupancy()
 
     def served_greedy(self) -> list:
-        """(prompt, tokens served by the window's close) of every greedy
-        request that was served a token, finished or not, for the
-        correctness check."""
+        """(prompt, tokens served) of every greedy request that was served
+        a token, finished or not, for the correctness check: served by the
+        window's close, or past it where the check needs more
+        (:meth:`_serve_past_close`)."""
         return [(np.asarray(r.prompt, np.int32),
                  np.asarray(r.out_tokens, np.int32))
                 for r in self.requests

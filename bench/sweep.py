@@ -47,3 +47,7 @@ def main() -> int:
                           "metrics": {k: v["value"] for k, v in
                                       line["metrics"].items()}}), flush=True)
     return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

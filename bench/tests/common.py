@@ -10,23 +10,28 @@ from bench import harness
 DATA = pathlib.Path(__file__).resolve().parent / "data"
 WORKLOAD = "tiny.open"
 
+DENSE = dict(arch="bench/archs/dense_decoder.py",
+             reference="bench/reference/dense_decoder.py")
+
 # qwen2-like (tied head, q/k/v bias, 6 query heads over 1 kv head) and
 # internlm2-like (untied head, no bias, 4 over 2), both tiny
-QWEN_LIKE = dict(name="tiny-qwen", source="test", hidden_size=96,
+QWEN_LIKE = dict(DENSE, name="tiny-qwen", source="test", hidden_size=96,
                  intermediate_size=160, num_attention_heads=6,
                  num_key_value_heads=1, num_hidden_layers=2, vocab_size=384,
                  rms_norm_eps=1e-6, rope_theta=1e6, tie_word_embeddings=True,
                  qkv_bias=True)
-INTERNLM_LIKE = dict(name="tiny-internlm", source="test", hidden_size=64,
-                     intermediate_size=128, num_attention_heads=4,
-                     num_key_value_heads=2, num_hidden_layers=3,
+INTERNLM_LIKE = dict(DENSE, name="tiny-internlm", source="test",
+                     hidden_size=64, intermediate_size=128,
+                     num_attention_heads=4, num_key_value_heads=2,
+                     num_hidden_layers=3,
                      vocab_size=320, rms_norm_eps=1e-5, rope_theta=1e6,
                      tie_word_embeddings=False, qkv_bias=False)
 
 
-# the tiny open-loop cell stands in for the chip's cell, metric for metric;
-# the closed loop, which no chip cell uses yet, reports what every cell does
-STANDS_FOR = {"qwen2-1.5b.chat": "tiny.open"}
+# each tiny cell stands in for the chip's cell of its arrivals, metric for
+# metric
+STANDS_FOR = {"qwen2-1.5b.chat": "tiny.open",
+              "internlm2-1.8b.docs": "tiny.closed"}
 
 
 TINY = ["tiny.open", "tiny.closed"]

@@ -10,7 +10,7 @@ import json
 import numpy as np
 import pytest
 
-from bench import check, serving, weights
+from bench import check, harness, weights
 from bench.tests.common import DATA
 
 CFG = json.loads((DATA / "configs" / "tiny.json").read_text())
@@ -20,7 +20,8 @@ LIMITS = json.loads((DATA / "limits" / "tiny.open.json").read_text())
 def served(seed: int) -> list:
     from repro.serve import ServeEngine
     geo = CFG["serve"]
-    eng = ServeEngine(serving.model_config(CFG), weights.make(CFG, seed),
+    eng = ServeEngine(harness.arch(CFG).program_config(CFG),
+                      weights.make(CFG, seed),
                       slots=geo["slots"], max_len=geo["max_len"],
                       page_size=geo["page_size"],
                       prefill_chunk=geo["prefill_chunk"])

@@ -4,6 +4,7 @@ from __future__ import annotations
 import json
 
 import numpy as np
+import pytest
 
 from bench import harness, loadgen
 from bench.tests.common import DATA
@@ -48,8 +49,11 @@ def test_warm_up_starts_with_little_law_requests_under_way():
     assert all(len(s.prompt) + s.max_new <= p["max"] + o["max"] for s in a)
 
 
-def test_closed_loop_same_work_every_seed():
-    m = json.loads((DATA / "traffic" / "tiny_closed.json").read_text())
+@pytest.mark.parametrize("path", [DATA / "traffic" / "tiny_closed.json",
+                                  harness.BENCH / "traffic" / "docs.json"],
+                         ids=lambda p: p.stem)
+def test_closed_loop_same_work_every_seed(path):
+    m = json.loads(path.read_text())
     fa, sa = loadgen.closed_loop(m, 1000, 4)
     fb, sb = loadgen.closed_loop(m, 1000, 2**31 + 7)
     assert len(fa) == m["clients"] and len(sa) == m["stream"]

@@ -14,8 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import serving, weights
-from bench.reference import dense_decoder
+from bench import harness, weights
 from bench.tests.common import INTERNLM_LIKE, QWEN_LIKE
 
 TOL = 2e-4          # float32 paths differ in summation order only
@@ -26,7 +25,8 @@ def program_logits(cfg: dict, seq: np.ndarray, dtype: str) -> np.ndarray:
     """Logits the serve steps give at positions ends of chunks and decode
     steps: prefill ``seq[:PROMPT]`` in chunks, then feed the rest."""
     from repro.launch.steps import build_serve_engine_steps
-    mcfg = dataclasses.replace(serving.model_config(cfg), dtype=dtype)
+    mcfg = dataclasses.replace(harness.arch(cfg).program_config(cfg),
+                               dtype=dtype)
     params = weights.make(cfg, 3)
     params = jax.tree.map(
         lambda a: a.astype(jnp.float32) if dtype == "float32" else a, params)
@@ -63,9 +63,10 @@ def positions() -> list:
                 ids=lambda c: c["name"])
 def case(request):
     cfg = request.param
+    reference = harness.reference(cfg)
     seq = np.random.default_rng(0).integers(0, cfg["vocab_size"],
-                                            dense_decoder.BLOCK)
-    ref = dense_decoder.logits(weights.make(cfg, 3), seq, cfg)
+                                            reference.BLOCK)
+    ref = reference.logits(weights.make(cfg, 3), seq, cfg)
     return cfg, seq, ref[positions()]
 
 
@@ -85,5 +86,5 @@ def test_lower_precision_references_are_caught(case):
     cfg, seq, ref = case
     w = weights.make(cfg, 3)
     for quant in ("bfloat16", "float8_e4m3fn"):
-        low = dense_decoder.logits(w, seq, cfg, quant)[positions()]
+        low = harness.reference(cfg).logits(w, seq, cfg, quant)[positions()]
         assert np.max(np.abs(low - ref)) > TOL * np.max(np.abs(ref)), quant

@@ -1,10 +1,10 @@
-"""Seeded random weights of a dense GQA decoder, made on the device.
+"""Seeded random weights of a configuration, made on the device.
 
 The benchmark, not the program, makes the weights: one jitted call from the
-seed, in the type they are served in (matrices bfloat16, norm scales
-float32), laid out as the program's parameter tree.  The plain reference
-calls the same function with the same seed, so it takes nothing that the
-program has made.
+seed, in the types and the layout of the program's parameter tree, as the
+configuration's architecture module gives them (``shapes``).  The plain
+reference calls the same function with the same seed, so it takes nothing
+that the program has made.
 
 Every leaf is drawn from its own key, ``fold_in(key(seed), leaf number)``,
 so a leaf does not depend on the others.  Matrices and biases are normal
@@ -22,44 +22,16 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from bench import harness
+
 STD = 0.02
 NORM_STD = 0.1
 SCORE_STD = 2.0
 
 
-def shapes(cfg: dict) -> dict:
-    """{path: (shape, dtype)} of the parameter tree, in a fixed order.
-
-    Paths follow the program's tree: ``groups/0/...`` leaves are stacked
-    over the layers on their first axis."""
-    d, f, v = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
-    hq, hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    dh = d // hq
-    n = cfg["num_hidden_layers"]
-    bf, f32 = jnp.bfloat16, jnp.float32
-    out = {"embed": ((v, d), bf), "final_norm/scale": ((d,), f32)}
-    if not cfg["tie_word_embeddings"]:
-        out["lm_head"] = ((d, v), bf)
-    g = "groups/0/"
-    out.update({
-        g + "mixer_norm/scale": ((n, d), f32),
-        g + "attn/wq": ((n, d, hq * dh), bf),
-        g + "attn/wk": ((n, d, hkv * dh), bf),
-        g + "attn/wv": ((n, d, hkv * dh), bf),
-        g + "attn/wo": ((n, hq * dh, d), bf),
-        g + "ffn_norm/scale": ((n, d), f32),
-        g + "mlp/wi_gate": ((n, d, f), bf),
-        g + "mlp/wi_up": ((n, d, f), bf),
-        g + "mlp/wo": ((n, f, d), bf),
-    })
-    if cfg["qkv_bias"]:
-        out.update({g + "attn/bq": ((n, hq * dh), bf),
-                    g + "attn/bk": ((n, hkv * dh), bf),
-                    g + "attn/bv": ((n, hkv * dh), bf)})
-    return out
-
-
 def _nest(flat: dict) -> dict:
+    """Paths to a tree; ``groups/<i>`` become the tuple ``groups``, one
+    entry for each position of the layer pattern."""
     tree: dict = {}
     for path, leaf in flat.items():
         node = tree
@@ -67,13 +39,14 @@ def _nest(flat: dict) -> dict:
         for k in head:
             node = node.setdefault(k, {})
         node[last] = leaf
-    tree["groups"] = (tree["groups"]["0"],)
+    groups = tree["groups"]
+    tree["groups"] = tuple(groups[str(i)] for i in range(len(groups)))
     return tree
 
 
 def make(cfg: dict, seed: int) -> dict:
     """The parameter tree for ``seed``, on the default device, one jit."""
-    spec = shapes(cfg)
+    spec = harness.arch(cfg).shapes(cfg)
 
     def build(key):
         flat = {}
